@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from fdtd3d_tpu import materials, physics
+from fdtd3d_tpu import materials, physics, telemetry
 from fdtd3d_tpu.layout import component_axis
 
 
@@ -180,23 +180,27 @@ def field_norms(sim) -> Dict[str, float]:
 
     Its own tiny jitted pass (max reductions only) — NOT the full
     metrics computation; reuses the full pass's result when one was
-    already computed at this step.
+    already computed at this step. The ``norms`` span covers the
+    unpack of a packed state, the pass and the readback.
     """
-    cache = getattr(sim, "_metrics_cache", None)
-    if cache is not None and cache[0] == sim.t:
-        dm = jax.device_get(cache[1])
-        return {c: float(dm[f"max_{c}"])
-                for g in ("E", "H") for c in sim.state[g]}
-    fn = getattr(sim, "_norms_device_fn", None)
-    if fn is None:
-        comps = [(g, c) for g in ("E", "H") for c in sim.state[g]]
+    with telemetry.span("norms"):
+        cache = getattr(sim, "_metrics_cache", None)
+        if cache is not None and cache[0] == sim.t:
+            dm = jax.device_get(cache[1])
+            return {c: float(dm[f"max_{c}"])
+                    for g in ("E", "H") for c in sim.state[g]}
+        fn = getattr(sim, "_norms_device_fn", None)
+        if fn is None:
+            comps = [(g, c) for g in ("E", "H") for c in sim.state[g]]
 
-        def compute(state):
-            return {c: jnp.max(jnp.abs(state[g][c])) for (g, c) in comps}
+            def compute(state):
+                return {c: jnp.max(jnp.abs(state[g][c]))
+                        for (g, c) in comps}
 
-        fn = jax.jit(compute)
-        sim._norms_device_fn = fn
-    return {c: float(v) for c, v in jax.device_get(fn(sim.state)).items()}
+            fn = jax.jit(compute)
+            sim._norms_device_fn = fn
+        return {c: float(v)
+                for c, v in jax.device_get(fn(sim.state)).items()}
 
 
 def divergence_e(sim) -> Dict[str, float]:

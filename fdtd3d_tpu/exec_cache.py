@@ -508,10 +508,25 @@ def jit_compile(key: ExecKey, fn, args_fn, donate: bool
     it must be re-read at lower time, not captured at call time.
     Keeping this in one place means a new ExecKey axis or donation
     rule cannot be threaded into one executor and missed in the
-    other."""
+    other. The executable is named ``jit_fdtd3d_chunk`` (its HLO
+    module, and the device trace's ``XLA Modules`` line) and kept for
+    ``telemetry.op_scopes()``."""
     import jax
-    jitted = jax.jit(fn, donate_argnums=0 if donate else ())
-    return get_or_compile(key, lambda: jitted.lower(*args_fn()))
+
+    from fdtd3d_tpu import telemetry
+    jitted = jax.jit(chunk_named(fn), donate_argnums=0 if donate else ())
+    compiled, info = get_or_compile(key, lambda: jitted.lower(*args_fn()))
+    telemetry.remember_executable(key.digest, compiled)
+    return compiled, info
+
+
+def chunk_named(fn):
+    """``fn`` under the stable name ``fdtd3d_chunk``: jit names the
+    module after the function, and a ``functools.partial`` reads as
+    ``_unknown``."""
+    def fdtd3d_chunk(*args):
+        return fn(*args)
+    return fdtd3d_chunk
 
 
 def _remember(dig: str, compiled) -> None:
@@ -596,7 +611,7 @@ def aot_compile_sharded(cfg, topo3: Tuple[int, int, int], mesh,
                    backend=backend_tag, donate=True,
                    avals_fp=avals_fingerprint(*args),
                    devices=mesh_device_ids(mesh))
-    jitted = jax.jit(fn, donate_argnums=0)
+    jitted = jax.jit(chunk_named(fn), donate_argnums=0)
     compiled, info = get_or_compile(key,
                                     lambda: jitted.lower(*args))
     return runner, compiled, info

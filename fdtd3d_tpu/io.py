@@ -966,23 +966,28 @@ def write_outputs(sim, step: int):
     """Dump every stored field component in each configured format.
 
     Multi-process: the gather below is COLLECTIVE (all ranks must call
-    it), the file writes happen on rank 0 only.
+    it), the file writes happen on rank 0 only. Spans: ``io-gather``
+    (device to host) and ``io-write`` (the format writers).
     """
     import jax
+
+    from fdtd3d_tpu import telemetry
     out = sim.cfg.output
-    fields = sim.fields()            # collective allgather
+    with telemetry.span("io-gather"):
+        fields = sim.fields()        # collective allgather
     if jax.process_index() != 0:
         return
     os.makedirs(out.save_dir, exist_ok=True)
     axes = sim.static.mode.active_axes
-    for comp, arr in fields.items():
-        base = os.path.join(out.save_dir, f"{comp}_t{step:06d}")
-        if "dat" in out.formats:
-            dump_dat(arr, base + ".dat", step=step)
-        if "txt" in out.formats:
-            dump_txt(arr, base + ".txt")
-        if "bmp" in out.formats:
-            dump_bmp(arr, base + ".bmp", axes)
+    with telemetry.span("io-write"):
+        for comp, arr in fields.items():
+            base = os.path.join(out.save_dir, f"{comp}_t{step:06d}")
+            if "dat" in out.formats:
+                dump_dat(arr, base + ".dat", step=step)
+            if "txt" in out.formats:
+                dump_txt(arr, base + ".txt")
+            if "bmp" in out.formats:
+                dump_bmp(arr, base + ".bmp", axes)
 
 
 def write_materials(sim):

@@ -691,6 +691,7 @@ class Patch(NamedTuple):
     delta: Any            # thin local delta array, owner-gated
     own: Any = None       # traced ownership bool (sharded axis) or None
     gstart: int = -1      # static GLOBAL plane (traced patches only)
+    scope: Optional[str] = None   # named scope of its H correction
 
 
 def fields_copy(fields):
@@ -1006,7 +1007,7 @@ def tfsf_patch(static, family: str, fields: Dict[str, jnp.ndarray],
                 collect.append(Patch(
                     c, axis, plane if own is None else loc,
                     jnp.broadcast_to(jnp.expand_dims(val, axis), pshape),
-                    own, plane))
+                    own, plane, "tfsf"))
     return out
 
 
@@ -1054,10 +1055,11 @@ def point_source_patch(static, fields, coeffs, t, collect=None):
         plane = jnp.zeros((1,) + tuple(fshape[1:]), fdt)
         plane = plane.at[0, idxs[1], idxs[2]].add(val)
         if owns[0] is None:
-            collect.append(Patch(c, 0, ps.position[0], plane))
+            collect.append(Patch(c, 0, ps.position[0], plane,
+                                 scope="source"))
         else:
             collect.append(Patch(c, 0, idxs[0], plane, owns[0],
-                                 ps.position[0]))
+                                 ps.position[0], "source"))
     return fields_add(out, c, tuple(idxs), val)
 
 

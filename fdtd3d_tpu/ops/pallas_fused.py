@@ -28,6 +28,7 @@ the x halo).
 
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -140,6 +141,12 @@ def _traced_patch_fix(static, out_H, c, p, a, s, db, coeffs,
     return out_H
 
 
+def _patch_scope(p):
+    """The named scope of the work a patch's H correction does: its
+    origin's (``Patch.scope``), so TFSF work is charged to ``tfsf``."""
+    return _named(p.scope) if p.scope else contextlib.nullcontext()
+
+
 def apply_patch_h_corrections(static, new_H, psi_H, patches, coeffs,
                               slabs, mesh_axes=None, mesh_shape=None):
     """Correct the kernel's H update for post-kernel E patches.
@@ -193,118 +200,120 @@ def apply_patch_h_corrections(static, new_H, psi_H, patches, coeffs,
             if d not in mode.e_components:
                 continue
             for p in patches:
-                if p.comp != d:
-                    continue
-                if p.own is not None:
-                    # sharded patch axis: traced local index + ownership
-                    out_H = _traced_patch_fix(
-                        static, out_H, c, p, a, s, db, coeffs,
-                        mesh_axes, mesh_shape, inv_dx, cdt, h_dtype)
-                    continue
-                b, start, delta = p.axis, p.start, p.delta
-                delta = delta.astype(cdt)
-                k = delta.shape[b]
-                # LOCAL extent: patches carry shard-local plane starts
-                # (identical to global when unsharded; the packed
-                # kernel also runs this under shard_map)
-                n_a = static.grid_shape[a] // static.topology[a]
-                if a == b:
-                    # forward diff along the patch normal: k+1 planes
-                    # starting at start-1 (zero ghost beyond the patch)
-                    pad = [(0, 0)] * 3
-                    pad[a] = (1, 1)
-                    vpad = jnp.pad(delta, pad)
-                    w = (lax.slice_in_dim(vpad, 1, k + 2, axis=a)
-                         - lax.slice_in_dim(vpad, 0, k + 1, axis=a)) \
-                        * inv_dx
-                    pstart = start - 1
-                    lo_clip = max(0, -pstart)
-                    hi_clip = min(k + 1, n_a - pstart)
-                    if hi_clip <= lo_clip:
+                with _patch_scope(p):
+                    if p.comp != d:
                         continue
-                    w = lax.slice_in_dim(w, lo_clip, hi_clip, axis=a)
-                    pstart += lo_clip
-                    plen = hi_clip - lo_clip
-                else:
-                    # in-patch forward diff along a (zero ghost at the
-                    # global hi edge — the kernel's PEC convention)
-                    w = (_shift_lo(delta, a) - delta) * inv_dx
-                    if mesh_axes and mesh_axes.get(a):
-                        # sharded axis: the local hi plane's forward
-                        # neighbor is the UPPER shard's first patch
-                        # plane (zeros arrive at the global edge)
-                        name = mesh_axes[a]
-                        n_sh = mesh_shape[name]
-                        first = lax.slice_in_dim(delta, 0, 1, axis=a)
-                        with _named("halo-exchange"):
-                            nxt = lax.ppermute(
-                                first, name,
-                                [(r + 1, r) for r in range(n_sh - 1)])
-                        n_loc = delta.shape[a]
-                        hi_sl = [slice(None)] * 3
-                        hi_sl[a] = slice(n_loc - 1, n_loc)
-                        w = w.at[tuple(hi_sl)].add(nxt * inv_dx)
-                    pstart, plen = start, k
-
-                # position of the correction along the patch-extent axis
-                pa = a if a == b else b
-                sl = [slice(None)] * 3
-                sl[pa] = slice(pstart, pstart + plen)
-                sl = tuple(sl)
-
-                if a in slabs and a != 0:
+                    if p.own is not None:
+                        # sharded patch axis: traced local index + ownership
+                        out_H = _traced_patch_fix(
+                            static, out_H, c, p, a, s, db, coeffs,
+                            mesh_axes, mesh_shape, inv_dx, cdt, h_dtype)
+                        continue
+                    b, start, delta = p.axis, p.start, p.delta
+                    delta = delta.astype(cdt)
+                    k = delta.shape[b]
+                    # LOCAL extent: patches carry shard-local plane starts
+                    # (identical to global when unsharded; the packed
+                    # kernel also runs this under shard_map)
+                    n_a = static.grid_shape[a] // static.topology[a]
                     if a == b:
-                        dacc = s * slab_f(a, pstart, pstart + plen) * w
+                        # forward diff along the patch normal: k+1 planes
+                        # starting at start-1 (zero ghost beyond the patch)
+                        pad = [(0, 0)] * 3
+                        pad[a] = (1, 1)
+                        vpad = jnp.pad(delta, pad)
+                        w = (lax.slice_in_dim(vpad, 1, k + 2, axis=a)
+                             - lax.slice_in_dim(vpad, 0, k + 1, axis=a)) \
+                            * inv_dx
+                        pstart = start - 1
+                        lo_clip = max(0, -pstart)
+                        hi_clip = min(k + 1, n_a - pstart)
+                        if hi_clip <= lo_clip:
+                            continue
+                        w = lax.slice_in_dim(w, lo_clip, hi_clip, axis=a)
+                        pstart += lo_clip
+                        plen = hi_clip - lo_clip
                     else:
-                        dacc = s * slab_f(a, 0, n_a) * w
-                    # stored psi' correction at the slab overlap
-                    key = f"{c}_{AXES[a]}"
-                    m = slabs[a]
-                    ca_prof = coeffs[f"pml_slab_ch_{AXES[a]}"]
-                    if a == b:
-                        # patch planes [pstart, pstart+plen) vs slabs
-                        # [0, m) and [n_a-m, n_a) -> compact [0,m)/[m,2m)
-                        for (s_lo, s_hi, c_off) in ((0, m, 0),
-                                                    (n_a - m, n_a, m)):
-                            o_lo = max(pstart, s_lo)
-                            o_hi = min(pstart + plen, s_hi)
-                            if o_hi <= o_lo:
-                                continue
-                            wsl = [slice(None)] * 3
-                            wsl[a] = slice(o_lo - pstart, o_hi - pstart)
-                            psl = [slice(None)] * 3
-                            psl[a] = slice(c_off + o_lo - s_lo,
-                                           c_off + o_hi - s_lo)
-                            cp = ca_prof[c_off + o_lo - s_lo:
-                                         c_off + o_hi - s_lo]
+                        # in-patch forward diff along a (zero ghost at the
+                        # global hi edge — the kernel's PEC convention)
+                        w = (_shift_lo(delta, a) - delta) * inv_dx
+                        if mesh_axes and mesh_axes.get(a):
+                            # sharded axis: the local hi plane's forward
+                            # neighbor is the UPPER shard's first patch
+                            # plane (zeros arrive at the global edge)
+                            name = mesh_axes[a]
+                            n_sh = mesh_shape[name]
+                            first = lax.slice_in_dim(delta, 0, 1, axis=a)
+                            with _named("halo-exchange"):
+                                nxt = lax.ppermute(
+                                    first, name,
+                                    [(r + 1, r) for r in range(n_sh - 1)])
+                            n_loc = delta.shape[a]
+                            hi_sl = [slice(None)] * 3
+                            hi_sl[a] = slice(n_loc - 1, n_loc)
+                            w = w.at[tuple(hi_sl)].add(nxt * inv_dx)
+                        pstart, plen = start, k
+
+                    # position of the correction along the patch-extent axis
+                    pa = a if a == b else b
+                    sl = [slice(None)] * 3
+                    sl[pa] = slice(pstart, pstart + plen)
+                    sl = tuple(sl)
+
+                    if a in slabs and a != 0:
+                        if a == b:
+                            dacc = s * slab_f(a, pstart, pstart + plen) * w
+                        else:
+                            dacc = s * slab_f(a, 0, n_a) * w
+                        # stored psi' correction at the slab overlap
+                        key = f"{c}_{AXES[a]}"
+                        m = slabs[a]
+                        ca_prof = coeffs[f"pml_slab_ch_{AXES[a]}"]
+                        if a == b:
+                            # patch planes [pstart, pstart+plen) vs slabs
+                            # [0, m) and [n_a-m, n_a) -> compact [0,m)/[m,2m)
+                            for (s_lo, s_hi, c_off) in ((0, m, 0),
+                                                        (n_a - m, n_a, m)):
+                                o_lo = max(pstart, s_lo)
+                                o_hi = min(pstart + plen, s_hi)
+                                if o_hi <= o_lo:
+                                    continue
+                                wsl = [slice(None)] * 3
+                                wsl[a] = slice(o_lo - pstart, o_hi - pstart)
+                                psl = [slice(None)] * 3
+                                psl[a] = slice(c_off + o_lo - s_lo,
+                                               c_off + o_hi - s_lo)
+                                cp = ca_prof[c_off + o_lo - s_lo:
+                                             c_off + o_hi - s_lo]
+                                shape = [1, 1, 1]
+                                shape[a] = o_hi - o_lo
+                                _p3.fields_add(
+                                    out_psi, key, tuple(psl),
+                                    cp.reshape(shape) * w[tuple(wsl)])
+                        else:
+                            # w spans full a; slice its slab planes, add at
+                            # the patch's b-location in the compact array
+                            wsl_lo = [slice(None)] * 3
+                            wsl_lo[a] = slice(0, m)
+                            wsl_hi = [slice(None)] * 3
+                            wsl_hi[a] = slice(n_a - m, n_a)
                             shape = [1, 1, 1]
-                            shape[a] = o_hi - o_lo
-                            _p3.fields_add(out_psi, key, tuple(psl),
-                                           cp.reshape(shape) * w[tuple(wsl)])
+                            shape[a] = m
+                            add = jnp.concatenate(
+                                [ca_prof[:m].reshape(shape)
+                                 * w[tuple(wsl_lo)],
+                                 ca_prof[m:].reshape(shape)
+                                 * w[tuple(wsl_hi)]], axis=a)
+                            bsl = [slice(None)] * 3
+                            bsl[b] = slice(pstart, pstart + plen)
+                            _p3.fields_add(out_psi, key, tuple(bsl), add)
                     else:
-                        # w spans full a; slice its slab planes, add at
-                        # the patch's b-location in the compact array
-                        wsl_lo = [slice(None)] * 3
-                        wsl_lo[a] = slice(0, m)
-                        wsl_hi = [slice(None)] * 3
-                        wsl_hi[a] = slice(n_a - m, n_a)
-                        shape = [1, 1, 1]
-                        shape[a] = m
-                        add = jnp.concatenate(
-                            [ca_prof[:m].reshape(shape)
-                             * w[tuple(wsl_lo)],
-                             ca_prof[m:].reshape(shape)
-                             * w[tuple(wsl_hi)]], axis=a)
-                        bsl = [slice(None)] * 3
-                        bsl[b] = slice(pstart, pstart + plen)
-                        _p3.fields_add(out_psi, key, tuple(bsl), add)
-                else:
-                    # plain curl term (x "post" axis or no PML on a)
-                    dacc = s * w
+                        # plain curl term (x "post" axis or no PML on a)
+                        dacc = s * w
 
-                db_sl = db[sl] if jnp.ndim(db) == 3 else db
-                _p3.fields_add(out_H, c, sl,
-                               (-db_sl * dacc).astype(h_dtype))
+                    db_sl = db[sl] if jnp.ndim(db) == 3 else db
+                    _p3.fields_add(out_H, c, sl,
+                                   (-db_sl * dacc).astype(h_dtype))
     return out_H, out_psi
 
 

@@ -73,6 +73,13 @@ class Simulation:
     """
 
     def __init__(self, cfg, devices: Optional[List] = None):
+        # set-up spans (telemetry.span_totals): sim-init covers the
+        # build; build-static / build-coeffs / make-runner are counted
+        # where they run (solver), coeffs-put and init-state here
+        with _telemetry.span("sim-init"):
+            self._build(cfg, devices)
+
+    def _build(self, cfg, devices):
         from fdtd3d_tpu.scenario import ScenarioSpec
         if isinstance(cfg, ScenarioSpec):
             self.spec = cfg
@@ -114,13 +121,18 @@ class Simulation:
             state_shapes = jax.eval_shape(lambda: init_state(self.static))
             self._coeff_specs = pmesh.coeff_specs(coeffs_np, topo)
             self._state_specs = pmesh.state_specs(state_shapes, topo)
-            self.coeffs = pmesh.shard_tree(coeffs_np, self._coeff_specs,
-                                           self.mesh)
-            self.state = pmesh.sharded_zeros(state_shapes,
-                                             self._state_specs, self.mesh)
+            with _telemetry.span("coeffs-put"):
+                self.coeffs = pmesh.shard_tree(coeffs_np, self._coeff_specs,
+                                               self.mesh)
+            with _telemetry.span("init-state"):
+                self.state = pmesh.sharded_zeros(state_shapes,
+                                                 self._state_specs,
+                                                 self.mesh)
         else:
-            self.coeffs = jax.tree.map(jnp.asarray, coeffs_np)
-            self.state = init_state(self.static)
+            with _telemetry.span("coeffs-put"):
+                self.coeffs = jax.tree.map(jnp.asarray, coeffs_np)
+            with _telemetry.span("init-state"):
+                self.state = init_state(self.static)
 
         self._mesh_axes = mesh_axes
         self._mesh_shape = mesh_shape
@@ -793,9 +805,14 @@ class Simulation:
         done = 0
         while done < total:
             n = min(interval, total - done)
-            self.advance(n)
+            # spans around the chunk's host side and the caller's work
+            # between chunks, so no idle gap of a traced run falls
+            # outside the program's names
+            with _telemetry.span("advance"):
+                self.advance(n)
             done += n
-            on_interval(self)
+            with _telemetry.span("on-interval"):
+                on_interval(self)
         return self
 
     @staticmethod

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from fdtd3d_tpu import materials, physics
-from fdtd3d_tpu.telemetry import named as _named
+from fdtd3d_tpu.telemetry import named as _named, span as _span
 from fdtd3d_tpu.config import SimConfig
 from fdtd3d_tpu.layout import CURL_TERMS, component_axis
 from fdtd3d_tpu.ops import cpml, tfsf
@@ -163,31 +163,32 @@ def _complex_backend_supported() -> bool:
 
 
 def build_static(cfg: SimConfig) -> StaticSetup:
-    cfg.validate()
-    paired = cfg.complex_fields and not _complex_backend_supported()
-    if cfg.dtype == "float64" and not jax.config.jax_enable_x64:
-        # The reference computes in C++ double; honor float64 requests
-        # instead of letting jax silently truncate to f32.
-        jax.config.update("jax_enable_x64", True)
-    mode = cfg.mode
-    # bfloat16 is a STORAGE dtype only (fields in HBM): coefficients,
-    # CPML psi, Drude J, the incident line, and all arithmetic stay f32
-    # (mixed precision) — bf16 accumulation of the leapfrog recursions
-    # loses the wave within tens of steps, while bf16 storage alone
-    # halves the HBM traffic that bounds FDTD throughput.
-    real = {"float32": np.float32, "float64": np.float64,
-            "bfloat16": np.float32, "float32x2": np.float32}[cfg.dtype]
-    field = cfg.np_dtype()
-    pml_axes = tuple(a for a in mode.active_axes if cfg.pml.size[a] > 0)
-    st = StaticSetup(
-        cfg=cfg, mode=mode, grid_shape=cfg.grid_shape, dt=cfg.dt,
-        dx=cfg.dx, omega=cfg.omega, pml_axes=pml_axes, tfsf_setup=None,
-        use_drude=cfg.materials.use_drude, field_dtype=field,
-        real_dtype=real, use_drude_m=cfg.materials.use_drude_m,
-        paired_complex=paired)
-    if cfg.tfsf.enabled:
-        st = dataclasses.replace(st, tfsf_setup=tfsf.build_setup(cfg, st))
-    return st
+    with _span("build-static"):
+        cfg.validate()
+        paired = cfg.complex_fields and not _complex_backend_supported()
+        if cfg.dtype == "float64" and not jax.config.jax_enable_x64:
+            # The reference computes in C++ double; honor float64 requests
+            # instead of letting jax silently truncate to f32.
+            jax.config.update("jax_enable_x64", True)
+        mode = cfg.mode
+        # bfloat16 is a STORAGE dtype only (fields in HBM): coefficients,
+        # CPML psi, Drude J, the incident line, and all arithmetic stay f32
+        # (mixed precision) — bf16 accumulation of the leapfrog recursions
+        # loses the wave within tens of steps, while bf16 storage alone
+        # halves the HBM traffic that bounds FDTD throughput.
+        real = {"float32": np.float32, "float64": np.float64,
+                "bfloat16": np.float32, "float32x2": np.float32}[cfg.dtype]
+        field = cfg.np_dtype()
+        pml_axes = tuple(a for a in mode.active_axes if cfg.pml.size[a] > 0)
+        st = StaticSetup(
+            cfg=cfg, mode=mode, grid_shape=cfg.grid_shape, dt=cfg.dt,
+            dx=cfg.dx, omega=cfg.omega, pml_axes=pml_axes, tfsf_setup=None,
+            use_drude=cfg.materials.use_drude, field_dtype=field,
+            real_dtype=real, use_drude_m=cfg.materials.use_drude_m,
+            paired_complex=paired)
+        if cfg.tfsf.enabled:
+            st = dataclasses.replace(st, tfsf_setup=tfsf.build_setup(cfg, st))
+        return st
 
 
 # --------------------------------------------------------------------------
@@ -195,6 +196,14 @@ def build_static(cfg: SimConfig) -> StaticSetup:
 # --------------------------------------------------------------------------
 
 def build_coeffs(static: StaticSetup) -> Dict[str, Any]:
+    """The host-built (numpy) coefficient pytree of ``static``, under
+    the ``build-coeffs`` span: every caller (Simulation, the kernel
+    factories) is counted."""
+    with _span("build-coeffs"):
+        return _build_coeffs(static)
+
+
+def _build_coeffs(static: StaticSetup) -> Dict[str, Any]:
     cfg, mode = static.cfg, static.mode
     shape = static.grid_shape
     dt, rd = static.dt, static.real_dtype
@@ -1297,6 +1306,16 @@ def _make_paired_complex_step(static: StaticSetup, mesh_axes=None,
 def make_chunk_runner(static: StaticSetup, mesh_axes=None, mesh_shape=None,
                       health: bool = False, per_chip: bool = False,
                       batch: int = 0):
+    """_make_chunk_runner under the ``make-runner`` span."""
+    with _span("make-runner"):
+        return _make_chunk_runner(static, mesh_axes, mesh_shape,
+                                  health=health, per_chip=per_chip,
+                                  batch=batch)
+
+
+def _make_chunk_runner(static: StaticSetup, mesh_axes=None,
+                       mesh_shape=None, health: bool = False,
+                       per_chip: bool = False, batch: int = 0):
     """scan-over-steps runner: run_chunk(state, coeffs, n) with static n.
 
     ``batch=B`` builds the lane-capable packed runner (make_step's

@@ -49,8 +49,10 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import re
 import socket
 import subprocess
+import threading
 import time
 from typing import Any, Dict, Optional, Sequence
 
@@ -139,12 +141,16 @@ HEALTH_KEYS = ("energy", "div_l2", "div_linf", "max_e", "max_h",
 PER_CHIP_KEYS = ("energy", "max_e", "max_h")
 
 # Span names as they appear in XProf (docs/OBSERVABILITY.md keeps the
-# one-line description of each). Host-side spans (TraceAnnotation):
+# one-line description of each). Host-side spans (TraceAnnotation, and
+# counted in span_totals()); the set-up ones run inside Simulation():
 HOST_SPANS = ("compile", "chunk", "pack", "vmem-ladder-rebuild",
-              "ntff-sample", "io-dump", "checkpoint", "telemetry-readback")
+              "ntff-sample", "io-dump", "checkpoint", "telemetry-readback",
+              "sim-init", "build-static", "build-coeffs", "coeffs-put",
+              "init-state", "make-runner", "norms", "io-gather",
+              "io-write", "advance", "on-interval")
 # In-graph scopes (named_scope; prefixed fdtd3d/ in the HLO metadata).
 # These are ALSO the attribution sections of the cost ledger
-# (fdtd3d_tpu/costs.py) and the trace parser
+# (fdtd3d_tpu/costs.py), of op_scopes() and of the trace parser
 # (tools/trace_attribution.py): every HLO op whose name stack carries
 # one of them is charged to that section.
 GRAPH_SPANS = ("E-update", "H-update", "cpml", "halo-exchange", "source",
@@ -159,16 +165,62 @@ GRAPH_SPANS = ("E-update", "H-update", "cpml", "halo-exchange", "source",
 GRAPH_SAFE_FNS = ("health", "_one")
 
 
+# name -> [count, seconds] of every host span closed in this process
+_SPAN_TOTALS: Dict[str, list] = {}
+_SPAN_LOCK = threading.Lock()
+
+
+class _Span:
+    """One host span: the profiler's annotation (on the device trace's
+    clock) plus its count and perf_counter duration in _SPAN_TOTALS."""
+
+    __slots__ = ("name", "_ann", "_t0")
+
+    def __init__(self, name: str, ann):
+        self.name = name
+        self._ann = ann
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        with _SPAN_LOCK:
+            tot = _SPAN_TOTALS.setdefault(self.name, [0, 0.0])
+            tot[0] += 1
+            tot[1] += dt
+        return self._ann.__exit__(*exc)
+
+
 def span(name: str):
     """Host-side trace span: wraps ``jax.profiler.TraceAnnotation`` so
-    XProf timelines show compile/dispatch/IO phases in domain terms.
-    Returns a context manager; a backend without the profiler API
-    degrades to a no-op."""
+    XProf timelines show compile/dispatch/IO phases in domain terms,
+    and adds its count and seconds to ``span_totals()``, which needs no
+    capture (set-up runs before any trace starts). Returns a context
+    manager; a backend without the profiler API keeps the count
+    only."""
     try:
         import jax
-        return jax.profiler.TraceAnnotation(f"fdtd3d/{name}")
+        ann = jax.profiler.TraceAnnotation(f"fdtd3d/{name}")
     except Exception:  # pragma: no cover - profiler API missing
-        return contextlib.nullcontext()
+        ann = contextlib.nullcontext()
+    return _Span(name, ann)
+
+
+def span_totals() -> Dict[str, Dict[str, float]]:
+    """``{name: {"count": n, "seconds": s}}`` of every host span closed
+    in this process since start (or ``reset_span_totals``); a nested
+    span counts in its own name and inside its parent's seconds."""
+    with _SPAN_LOCK:
+        return {k: {"count": int(n), "seconds": float(s)}
+                for k, (n, s) in _SPAN_TOTALS.items()}
+
+
+def reset_span_totals() -> None:
+    with _SPAN_LOCK:
+        _SPAN_TOTALS.clear()
 
 
 def named(name: str):
@@ -177,6 +229,144 @@ def named(name: str):
     the HLO metadata XProf groups by."""
     import jax
     return jax.named_scope(f"fdtd3d/{name}")
+
+
+# --------------------------------------------------------------------------
+# scope map of the chunk executables (device ops -> named scopes)
+# --------------------------------------------------------------------------
+
+# The chunk executables compiled or loaded in this process, by exec-cache
+# digest (exec_cache.jit_compile registers them), and their parsed maps.
+_EXECUTABLES: Dict[str, Any] = {}
+_PARSED: Dict[str, Dict[str, Dict[str, tuple]]] = {}
+_EXEC_LOCK = threading.Lock()
+EXECUTABLES_CAP = 16
+
+_SCOPE_RE = re.compile(r"fdtd3d/([\w-]+)")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_CALLED_RE = re.compile(r"\b(?:calls|to_apply)=(\{[^}]*\}|%?[\w.\-]+)")
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%?([^\s(]+)\s*\(.*\{\s*$")
+_INSTRUCTION_RE = re.compile(r"^\s+(?:ROOT\s+)?%?([^\s=]+)\s*=\s*(.*)$")
+
+
+def remember_executable(key: str, compiled) -> None:
+    """Keep a reference to a chunk executable for ``op_scopes()``; the
+    oldest goes past EXECUTABLES_CAP."""
+    with _EXEC_LOCK:
+        _EXECUTABLES.pop(key, None)
+        _EXECUTABLES[key] = compiled
+        while len(_EXECUTABLES) > EXECUTABLES_CAP:
+            old = next(iter(_EXECUTABLES))
+            _EXECUTABLES.pop(old)
+            _PARSED.pop(old, None)
+
+
+def _innermost(op_name: Optional[str]) -> Optional[str]:
+    found = _SCOPE_RE.findall(op_name or "")
+    return found[-1] if found else None
+
+
+def parse_hlo_scopes(text: str) -> Dict[str, Dict[str, tuple]]:
+    """Optimised HLO text -> ``{module: {instruction: (scope, fused)}}``.
+
+    ``scope`` is the innermost ``fdtd3d/<name>`` of the instruction's
+    ``op_name`` (None without one); ``fused`` is the frozenset of the
+    scopes of the instructions inside the computations it calls (a
+    fusion's parts, an async op's wrapped op), empty for a plain op.
+    The instruction names are the op names of the device trace."""
+    module = None
+    out: Dict[str, Dict[str, tuple]] = {}
+    comps: Dict[str, list] = {}      # computation -> [(scope, called)]
+    insts: list = []                 # (module, name, scope, called)
+    cur = None
+    for line in text.splitlines():
+        if line.startswith("HloModule"):
+            module = line.split()[1].rstrip(",")
+            out.setdefault(module, {})
+            continue
+        m = _COMPUTATION_RE.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+            continue
+        m = _INSTRUCTION_RE.match(line)
+        if m is None or cur is None or module is None:
+            continue
+        rest = m.group(2)
+        op = _OP_NAME_RE.search(rest)
+        scope = _innermost(op.group(1) if op else None)
+        called = []
+        for c in _CALLED_RE.findall(rest):
+            called += [s.strip().lstrip("%") for s in c.strip("{}")
+                       .split(",") if s.strip()]
+        cur.append((scope, called))
+        insts.append((module, m.group(1), scope, called))
+    memo: Dict[str, frozenset] = {}
+
+    def scopes_in(comp: str) -> frozenset:
+        if comp not in memo:
+            memo[comp] = frozenset()    # a cycle reads as empty
+            acc = set()
+            for scope, called in comps.get(comp, ()):
+                if scope is not None:
+                    acc.add(scope)
+                for c in called:
+                    acc |= scopes_in(c)
+            memo[comp] = frozenset(acc)
+        return memo[comp]
+
+    for mod, name, scope, called in insts:
+        fused = frozenset().union(*(scopes_in(c) for c in called))
+        out[mod][name] = (scope, fused)
+    return out
+
+
+def _hlo_text(compiled) -> str:
+    """The executable's optimised HLO (also given by one loaded from
+    the persistent or the exec cache); "" with a warning where the
+    backend gives none, since a missing map must not fail the run that
+    asks for it."""
+    try:
+        text = compiled.as_text()
+    except Exception as exc:  # the backend keeps no HLO for it
+        text = None
+        why = repr(exc)
+    else:
+        why = "empty text"
+    if text:
+        return text
+    from fdtd3d_tpu.log import warn
+    warn(f"op_scopes: no HLO text for a chunk executable ({why})")
+    return ""
+
+
+def op_scopes() -> Dict[str, Dict[str, tuple]]:
+    """``{module_name: {instruction_name: (scope, scopes_fused_in)}}``
+    for every chunk executable this process compiled or loaded (see
+    ``parse_hlo_scopes``); built from the optimised HLO the first time
+    it is asked for, never while the program runs. The trace's
+    ``XLA Modules`` line names an executable ``<module_name>(<id>)`` and
+    its ``XLA Ops`` line names each op by its instruction. Executables
+    that share a module name (chunks of different lengths) share one
+    table; an instruction they scope differently has scope None and
+    both scopes in its fused set."""
+    with _EXEC_LOCK:
+        for key, compiled in _EXECUTABLES.items():
+            if key not in _PARSED:
+                _PARSED[key] = parse_hlo_scopes(_hlo_text(compiled))
+        maps = [_PARSED[key] for key in _EXECUTABLES]
+    out: Dict[str, Dict[str, tuple]] = {}
+    for parsed in maps:
+        for mod, table in parsed.items():
+            into = out.setdefault(mod, {})
+            for name, entry in table.items():
+                had = into.get(name)
+                if had is None or had == entry:
+                    into[name] = entry
+                else:
+                    both = {had[0], entry[0]} - {None}
+                    into[name] = (had[0] if had[0] == entry[0] else None,
+                                  had[1] | entry[1] | frozenset(both))
+    return out
 
 
 # --------------------------------------------------------------------------

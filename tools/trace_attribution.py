@@ -11,10 +11,18 @@ or ``jax.profiler.trace``):
 It parses the trace-viewer JSON (``*.trace.json[.gz]`` under
 ``plugins/profile/<session>/``), sums the duration of every event whose
 name or args carry a ``fdtd3d/<section>`` scope — host
-``TraceAnnotation`` spans and (on TPU) device op events whose HLO
-metadata carries the ``jax.named_scope`` stack — and reports measured
-time per section (innermost scope wins, matching the cost ledger's
-attribution rule). With ``--ledger`` the modeled shares sit next to the
+``TraceAnnotation`` spans and device op events that carry the
+``jax.named_scope`` stack — and reports measured time per section
+(innermost scope wins, matching the cost ledger's attribution rule).
+On a TPU v5 lite the stack is an op event's ``tf_op`` arg, and not
+every op event has one (two thirds of them had none in a 512^3 Mie
+window); a fusion's names only its root op, and XLA:CPU op events
+carry none.
+``telemetry.op_scopes()`` names every op of the chunk executable
+(``jit_fdtd3d_chunk``) from its optimised HLO, fused parts included,
+and ``telemetry.span_totals()`` counts the host spans, set-up ones
+(``sim-init``, ``build-coeffs`` ...) included, with no capture at all
+(docs/OBSERVABILITY.md). With ``--ledger`` the modeled shares sit next to the
 measured ones in a single merged artifact: one telemetry schema-v2
 ``attribution`` record, validated by ``telemetry.validate_record``.
 
