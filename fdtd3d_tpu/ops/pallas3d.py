@@ -862,55 +862,20 @@ def plane_corrections(field: str, comp: str, setup, coeffs, inc,
                       active_axes, dx: float):
     """TFSF corrections as (axis, plane, broadcastable term) patches.
 
-    Same math as ops/tfsf.corrections_for but WITHOUT the full-size onehot
-    gate — the plane index is returned for an .at[plane].add patch. All
-    coordinates come from the gx/gy/gz global-index arrays, so the terms
-    are correct per shard.
+    ``tfsf.corr_plane_term``'s face-plane terms (the one authority for
+    the f32 sampling and the transverse gate) with the plane index for
+    an .at[plane].add patch, in place of corrections_for's full-size
+    onehot. All coordinates come from the gx/gy/gz global-index
+    arrays, so the terms are correct per shard.
     """
-    gs = (coeffs["gx"], coeffs["gy"], coeffs["gz"])
-    # zeta is a REAL line coordinate (see tfsf.corrections_for)
-    rdt = jnp.real(inc["Einc"]).dtype
     out = []
     for corr in setup.corrections:
         if corr.field != field or corr.comp != comp:
             continue
-        off = tfsf_mod.YEE_OFFSETS[corr.src]
-        zeta = setup.zeta0 + setup.khat[corr.axis] * (
-            corr.pos_a - setup.origin[corr.axis])
-        zeta = jnp.asarray(zeta, dtype=rdt)
-        for b in range(3):
-            if b == corr.axis or b not in active_axes:
-                continue
-            pb = gs[b].astype(rdt) + off[b]
-            shape = [1, 1, 1]
-            shape[b] = pb.shape[0]
-            # khat/origin are strong-typed f64 scalars: cast to rdt so
-            # an f32 run stays f32 even with jax_enable_x64 on
-            zeta = zeta + jnp.asarray(setup.khat[b], rdt) * (
-                pb - jnp.asarray(setup.origin[b], rdt)).reshape(shape)
-        if corr.src[0] == "E":
-            val = tfsf_mod._interp_line(inc["Einc"], zeta)
-            pol = setup.ehat[component_axis(corr.src)]
-        else:
-            val = tfsf_mod._interp_line(inc["Hinc"], zeta - 0.5)
-            pol = setup.hhat[component_axis(corr.src)]
-        if abs(pol) < tfsf_mod.POL_EPS:
-            continue
-        gate = None
-        m_off = tfsf_mod.YEE_OFFSETS[corr.mask_comp]
-        for b in range(3):
-            if b == corr.axis or b not in active_axes:
-                continue
-            hi_b = setup.hi[b] - 1 if m_off[b] == 0.5 else setup.hi[b]
-            ind = (gs[b] >= setup.lo[b]) & (gs[b] <= hi_b)
-            shape_b = [1, 1, 1]
-            shape_b[b] = ind.shape[0]
-            ind = ind.reshape(shape_b).astype(val.dtype)
-            gate = ind if gate is None else gate * ind
-        term = jnp.asarray(corr.sign * pol / dx, rdt) * val
-        if gate is not None:
-            term = term * gate
-        out.append((corr.axis, corr.plane, term))
+        term = tfsf_mod.corr_plane_term(corr, setup, coeffs, inc,
+                                        active_axes, dx)
+        if term is not None:
+            out.append((corr.axis, corr.plane, term))
     return out
 
 

@@ -30,6 +30,10 @@ Shard-safety: every correction is (one-hot 1D mask along a) x (transverse
 slab of interpolated incident values). Both are computed from the SHARDED
 1D global-coordinate arrays in the coeffs pytree, so the same code runs
 single-chip and under shard_map; the incident line itself is replicated.
+The slab is sampled off the line only along the transverse axes khat
+varies on (``_sample_incident``) and broadcast to the face plane by the
+transverse box gate: at normal incidence a face samples one value or one
+row of n, not n^2 positions.
 
 Time alignment: Einc is advanced to t^{n+1} BEFORE the main E update (which
 consumes Hinc at t^{n+1/2}); Hinc advances after the E update. This mirrors
@@ -39,6 +43,7 @@ the reference's performPlaneWaveESteps-before-field-steps ordering
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from typing import Dict, List, Optional, Tuple
@@ -55,11 +60,14 @@ _TAIL = 24  # absorbing-tail length on the incident line, cells
 # Polarization-projection cutoff: a correction whose ehat/hhat projection
 # is below this is identically zero physics (an exact geometric zero
 # blurred by the basis construction's f64 rounding) and is dropped. The
-# SINGLE authority for that threshold — corrections_for/record_term_ds
-# here, pallas3d.plane_corrections, and the packed-ds kernel's static
-# record filter (pallas_packed_ds._corr_records) must all agree, or a
+# SINGLE authority for that threshold — corr_plane_term/record_term_ds
+# here (pallas3d.plane_corrections goes through corr_plane_term), and
+# the packed-ds kernel's static record filter
+# (pallas_packed_ds._corr_records) must all agree, or a
 # record could be pre-filtered by one layer and then crash or silently
-# vanish in another (advisor finding r5-2).
+# vanish in another (advisor finding r5-2). A khat component below it
+# is the same kind of zero: the f32 sampler skips its axis
+# (_sample_incident).
 POL_EPS = 1e-14
 
 
@@ -262,8 +270,32 @@ def _advance_hinc_ds(inc, coeffs, setup: TfsfSetup):
     return dict(inc, Hinc=hh, Hinc_lo=hl)
 
 
+# Trace-time tallies of incident-line positions (count_line_samples).
+_SAMPLE_TALLIES: List[List[int]] = []
+
+
+@contextlib.contextmanager
+def count_line_samples():
+    """Count the incident-line positions sampled by the code traced in
+    the block: yields a one-element list that holds the count. The
+    chunk runner reads it per traced step (the ``tfsf-line-samples``
+    gauge)."""
+    tally = [0]
+    _SAMPLE_TALLIES.append(tally)
+    try:
+        yield tally
+    finally:
+        _SAMPLE_TALLIES.remove(tally)
+
+
+def _tally_samples(u) -> None:
+    for tally in _SAMPLE_TALLIES:
+        tally[0] += int(np.size(u))
+
+
 def _interp_line(line: jnp.ndarray, u: jnp.ndarray) -> jnp.ndarray:
     """Linear interpolation of the 1D line at fractional index u (clipped)."""
+    _tally_samples(u)
     u = jnp.clip(u, 0.0, line.shape[0] - 1.001)
     i0 = jnp.floor(u).astype(jnp.int32)
     w = (u - i0.astype(u.dtype))
@@ -276,10 +308,10 @@ def _corr_gate_transverse(corr: Correction, setup: TfsfSetup, gs,
     broadcastable 0/1 mask, or None when no transverse axis is active.
     THE single authority for which cells a correction touches
     transversely — consumed by corr_plane_term (f32: the jnp step AND
-    the temporal-blocked kernel's plane-value operands), by
-    record_term_ds (float32x2), and by consumers that carry the normal
-    plane index statically (the packed-ds kernel's per-plane records;
-    mirrored by pallas3d.plane_corrections' patch gating) — so the
+    the temporal-blocked kernel's plane-value operands and
+    pallas3d.plane_corrections' patches), by record_term_ds
+    (float32x2), and by consumers that carry the normal plane index
+    statically (the packed-ds kernel's per-plane records) — so the
     box-membership rule (half-offset components occupy [lo, hi-1])
     can never drift between paths."""
     gate = None
@@ -296,30 +328,35 @@ def _corr_gate_transverse(corr: Correction, setup: TfsfSetup, gs,
     return gate
 
 
-def corr_plane_term(corr: Correction, setup: TfsfSetup, coeffs,
-                    inc: Dict[str, jnp.ndarray], active_axes,
-                    dx: float) -> Optional[jnp.ndarray]:
-    """ONE correction's accumulator term on its face plane — the
-    transverse box gate applied but WITHOUT the normal-axis onehot —
-    or None when the polarization projection vanishes (POL_EPS).
+def _projection(corr: Correction, setup: TfsfSetup) -> float:
+    """The incidence polarization's projection on ``corr``'s sampled
+    component (a correction below POL_EPS is dropped)."""
+    pol = setup.ehat if corr.src[0] == "E" else setup.hhat
+    return pol[component_axis(corr.src)]
 
-    The single authority for the per-correction f32 math (the ds twin
-    is record_term_ds): corrections_for consumes it through the
-    normal-axis onehot, and the temporal-blocked kernel
-    (ops/pallas_packed_tb.py) consumes it directly as per-generation
-    plane-value operands, carrying the plane index statically — so the
-    jnp and in-kernel paths cannot drift."""
-    gs = (coeffs["gx"], coeffs["gy"], coeffs["gz"])
+
+def _sample_incident(corr: Correction, setup: TfsfSetup, gs,
+                     inc: Dict[str, jnp.ndarray], active_axes):
+    """The incident value ``corr`` reads off the f32 line at its
+    staggered sample positions, sampled only along the transverse
+    axes the plane wave varies on: a scalar, or an array with a
+    non-unit extent on each transverse active axis b where
+    |khat[b]| >= POL_EPS, broadcastable to the face plane.
+
+    Skipping a smaller khat[b] leaves the f32 line coordinate zeta
+    unchanged bit for bit: khat[b] * (p_b - origin_b) is then below
+    half an ulp of zeta >= 1 (the zeta0 guard), so the sum rounds back
+    to zeta."""
     # zeta is a REAL line coordinate even when the fields are complex
     # (complex_fields mode): interpolation clips/floors it.
     rdt = jnp.real(inc["Einc"]).dtype
-    # zeta at the sample position, as broadcastable sum of 1D arrays.
     off = YEE_OFFSETS[corr.src]
     zeta = setup.zeta0 + setup.khat[corr.axis] * (
         corr.pos_a - setup.origin[corr.axis])
     zeta = jnp.asarray(zeta, dtype=rdt)
     for b in range(3):
-        if b == corr.axis or b not in active_axes:
+        if b == corr.axis or b not in active_axes \
+                or abs(setup.khat[b]) < POL_EPS:
             continue
         pb = gs[b].astype(rdt) + off[b]
         shape = [1, 1, 1]
@@ -329,17 +366,33 @@ def corr_plane_term(corr: Correction, setup: TfsfSetup, coeffs,
         zeta = zeta + jnp.asarray(setup.khat[b], rdt) * (
             pb - jnp.asarray(setup.origin[b], rdt)).reshape(shape)
     if corr.src[0] == "E":
-        val = _interp_line(inc["Einc"], zeta)
-        pol = setup.ehat[component_axis(corr.src)]
-    else:
-        # Hinc samples live at half positions on the line.
-        val = _interp_line(inc["Hinc"], zeta - 0.5)
-        pol = setup.hhat[component_axis(corr.src)]
+        return _interp_line(inc["Einc"], zeta)
+    # Hinc samples live at half positions on the line.
+    return _interp_line(inc["Hinc"], zeta - 0.5)
+
+
+def corr_plane_term(corr: Correction, setup: TfsfSetup, coeffs,
+                    inc: Dict[str, jnp.ndarray], active_axes,
+                    dx: float) -> Optional[jnp.ndarray]:
+    """ONE correction's accumulator term on its face plane — the
+    transverse box gate applied but WITHOUT the normal-axis onehot —
+    or None when the polarization projection vanishes (POL_EPS).
+
+    The single authority for the per-correction f32 math (the ds twin
+    is record_term_ds): corrections_for consumes it through the
+    normal-axis onehot, pallas3d.plane_corrections as plane patches,
+    and the temporal-blocked kernel (ops/pallas_packed_tb.py) directly
+    as per-generation plane-value operands, carrying the plane index
+    statically — so the jnp and in-kernel paths cannot drift."""
+    pol = _projection(corr, setup)
     if abs(pol) < POL_EPS:
         return None
+    gs = (coeffs["gx"], coeffs["gy"], coeffs["gz"])
+    val = _sample_incident(corr, setup, gs, inc, active_axes)
     gate = _corr_gate_transverse(corr, setup, gs, active_axes,
                                  val.dtype)
-    term = jnp.asarray(corr.sign * pol / dx, rdt) * val
+    term = jnp.asarray(corr.sign * pol / dx,
+                       jnp.real(inc["Einc"]).dtype) * val
     return term if gate is None else term * gate
 
 
@@ -416,6 +469,9 @@ def record_term_ds(corr: Correction, setup: TfsfSetup, coeffs, inc,
     cannot drift.
     """
     from fdtd3d_tpu.ops import ds
+    pol = _projection(corr, setup)
+    if abs(pol) < POL_EPS:
+        return None
     gs = (coeffs["gx"], coeffs["gy"], coeffs["gz"])
     rdt = inc["Einc"].dtype
     off = YEE_OFFSETS[corr.src]
@@ -441,13 +497,9 @@ def record_term_ds(corr: Correction, setup: TfsfSetup, coeffs, inc,
     if corr.src[0] == "E":
         vh, vl = _interp_line_ds(inc["Einc"], inc["Einc_lo"],
                                  (zh, zl))
-        pol = setup.ehat[component_axis(corr.src)]
     else:
         vh, vl = _interp_line_ds(inc["Hinc"], inc["Hinc_lo"],
                                  ds.add_f(zh, zl, np.float32(-0.5)))
-        pol = setup.hhat[component_axis(corr.src)]
-    if abs(pol) < POL_EPS:
-        return None
     ch, cl = ds.from_f64(np.float64(corr.sign) * pol / dx)
     th, tl = ds.mul_ff(vh, vl, ch, cl)
     gate = _corr_gate_transverse(corr, setup, gs, active_axes, th.dtype)
@@ -468,6 +520,7 @@ def _interp_line_ds(line_h, line_l, u_pair):
     """
     from fdtd3d_tpu.ops import ds
     uh, ul = u_pair
+    _tally_samples(uh)
     u = jnp.clip(uh + ul, 0.0, line_h.shape[0] - 1.001)
     i0 = jnp.floor(u).astype(jnp.int32)
     wh, we = ds.two_diff(uh, i0.astype(uh.dtype))

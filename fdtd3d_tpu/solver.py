@@ -35,7 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from fdtd3d_tpu import materials, physics
-from fdtd3d_tpu.telemetry import named as _named, span as _span
+from fdtd3d_tpu.telemetry import (named as _named, set_gauge as _set_gauge,
+                                  span as _span)
 from fdtd3d_tpu.config import SimConfig
 from fdtd3d_tpu.layout import CURL_TERMS, component_axis
 from fdtd3d_tpu.ops import cpml, tfsf
@@ -1390,7 +1391,12 @@ def _make_chunk_runner(static: StaticSetup, mesh_axes=None,
             cc = coeffs
 
         def body(s, _):
-            return step(s, cc), None
+            # traced once per chunk program: the per-step count of
+            # incident-line samples (a k-step call holds k generations)
+            with tfsf.count_line_samples() as sampled:
+                out = step(s, cc)
+            _set_gauge("tfsf-line-samples", sampled[0] / spc)
+            return out, None
         if spc > 1:
             nb, rem = divmod(n, spc)
             out, _ = jax.lax.scan(body, state, None, length=nb)

@@ -165,8 +165,15 @@ GRAPH_SPANS = ("E-update", "H-update", "cpml", "halo-exchange", "source",
 GRAPH_SAFE_FNS = ("health", "_one")
 
 
+# Gauges (set_gauge / gauges()), set when the chunk program is traced:
+# ``tfsf-line-samples``: incident-line positions the TFSF corrections
+# sample per simulated step, over every correction and generation.
+GAUGES = ("tfsf-line-samples",)
+
 # name -> [count, seconds] of every host span closed in this process
 _SPAN_TOTALS: Dict[str, list] = {}
+# name -> value of every gauge set in this process
+_GAUGES: Dict[str, float] = {}
 _SPAN_LOCK = threading.Lock()
 
 
@@ -221,6 +228,20 @@ def span_totals() -> Dict[str, Dict[str, float]]:
 def reset_span_totals() -> None:
     with _SPAN_LOCK:
         _SPAN_TOTALS.clear()
+
+
+def set_gauge(name: str, value: float) -> None:
+    """Set one of the program's GAUGES; a later set replaces it."""
+    with _SPAN_LOCK:
+        _GAUGES[name] = float(value)
+
+
+def gauges() -> Dict[str, float]:
+    """``{name: value}`` of the GAUGES set in this process: quantities
+    of the compiled program, set when it is traced (a re-trace
+    overwrites, never adds), read with no capture."""
+    with _SPAN_LOCK:
+        return dict(_GAUGES)
 
 
 def named(name: str):

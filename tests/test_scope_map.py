@@ -1,7 +1,7 @@
 """The program's own names for device ops and set-up time: the host span
 counters (telemetry.span_totals), the scope map of the chunk executable
-(telemetry.op_scopes) and the benchmark readers built on them
-(benchmark/metrics/)."""
+(telemetry.op_scopes), the program's gauges (telemetry.gauges) and the
+benchmark readers built on them (benchmark/metrics/)."""
 
 import os
 import sys
@@ -17,7 +17,8 @@ sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 
 import tracing  # noqa: E402
 from metrics import (_scopes, coeffs_builds, coeffs_s,  # noqa: E402
-                     dump_write_ms, per_chunk_ms, tfsf_scope_ms_per_step)
+                     dump_write_ms, per_chunk_ms, tfsf_line_samples,
+                     tfsf_scope_ms_per_step)
 
 ARGS = ["--3d", "--same-size", "16", "--time-steps", "4", "--use-pml",
         "--pml-size", "3", "--use-tfsf", "--tfsf-margin", "3",
@@ -33,8 +34,13 @@ def _sim():
 @pytest.fixture(scope="module")
 def stepped():
     """A small TFSF+CPML sim built from fresh counters and advanced one
-    chunk: -> (sim, span totals of the build, op_scopes())."""
+    chunk: -> (sim, span totals of the build, op_scopes()). The
+    registry of chunk executables starts empty, so that op_scopes()
+    holds this sim's chunk alone and not the executables that other
+    tests of the same process compiled under the same module name."""
     telemetry.reset_span_totals()
+    telemetry._EXECUTABLES.clear()
+    telemetry._PARSED.clear()
     sim = _sim()
     built = telemetry.span_totals()
     sim.advance(1)
@@ -325,3 +331,26 @@ def test_readers_without_their_ops_or_spans(monkeypatch):
     for reader in (tfsf_scope_ms_per_step, per_chunk_ms, coeffs_s,
                    coeffs_builds, dump_write_ms):
         assert reader.read(ctx) is None, reader.__name__
+
+
+@pytest.mark.parametrize("gauges,want", [
+    (None, None),                             # a program without gauges
+    ({}, None),                               # the gauge never set
+    ({"tfsf-line-samples": 1028.0}, 1028.0),
+])
+def test_line_samples_reader(monkeypatch, gauges, want):
+    if gauges is None:
+        monkeypatch.delattr(telemetry, "gauges")
+    else:
+        monkeypatch.setattr(telemetry, "gauges", lambda: dict(gauges))
+    assert tfsf_line_samples.read(_ctx()) == want
+
+
+def test_gauges_are_set_not_summed(monkeypatch):
+    monkeypatch.setattr(telemetry, "_GAUGES", {})
+    telemetry.set_gauge("tfsf-line-samples", 100)
+    telemetry.set_gauge("tfsf-line-samples", 100)
+    snap = telemetry.gauges()
+    assert snap == {"tfsf-line-samples": 100.0}
+    snap["tfsf-line-samples"] = 0.0
+    assert telemetry.gauges()["tfsf-line-samples"] == 100.0
