@@ -1,5 +1,7 @@
 """What decides ``correct``: the timed path's own output against the
-plain reference (benchmark/reference/yee.py), at the timed size.
+plain reference that the configuration names (its ``reference`` key, a
+module under benchmark/ with the contract set out in
+benchmark/reference/yee.py), at the timed size.
 
 Nothing a check needs runs inside the window: each check reads what it
 compares once the window's clock has stopped (``after_window``),
@@ -20,6 +22,11 @@ traffic file picks one of three checks by ``check.kind``:
 
 A gap is max |program - reference| over a family's components, over
 the largest |reference| of that family: one number for E, one for H.
+
+The program's whole state goes to the reference, in the global layout
+(``layout.global_psi``); the reference refuses a family it does not
+model. On a cell's mesh every whole-grid array of the check lives
+sharded as the program's fields do (``layout.Layout``).
 """
 
 import gc
@@ -31,22 +38,34 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import layout
 import seeding
-from reference.yee import E_COMPS, H_COMPS, YeeReference
 
 
-def make(check_spec, seed):
+def make(check_spec, seed, reference):
+    """The traffic's check, comparing against ``reference`` (the loaded
+    module the configuration names)."""
     kind = check_spec["kind"]
     cls = {"segment": Segment, "dump": Dump, "trajectory": Trajectory}
     if kind not in cls:
         raise ValueError(f"unknown check kind {kind!r}")
-    return cls[kind](check_spec, seed)
+    return cls[kind](check_spec, seed, reference)
 
 
 class Check:
-    def __init__(self, spec, seed):
+    def __init__(self, spec, seed, reference):
         self.spec = spec
         self.seed = seed
+        self.ref_mod = reference
+
+    def from_program(self, ref, w, state):
+        """The reference's inputs from a program state dict: its psi
+        turned into the global layout, then handed over whole; the
+        reference checks what it receives."""
+        widths = {k: v.shape[layout.psi_axis(k)] for k, v in
+                  jax.eval_shape(ref.zero_aux)["psi"].items()}
+        return ref.from_program(layout.global_psi(
+            state, widths, w.sim.topology, w.layout))
 
     def after_window(self, w):
         """Read what the comparison needs, once the window's clock has
@@ -63,32 +82,26 @@ def _max_abs(a):
     return jnp.max(jnp.abs(a.astype(jnp.float32)))
 
 
-def family_gaps(prog, ref, prefix):
-    """{prefix_E: gap, prefix_H: gap} of two {comp: array} dicts."""
+def family_gaps(prog, ref, prefix, ref_mod):
+    """{prefix_E: gap, prefix_H: gap} of two {comp: array} dicts, over
+    the E and H components of the reference module ``ref_mod``; sharded
+    arrays reduce in place."""
     out = {}
-    for fam, comps in (("E", E_COMPS), ("H", H_COMPS)):
+    for fam, comps in (("E", ref_mod.E_COMPS), ("H", ref_mod.H_COMPS)):
         scale = max(float(_max_abs(ref[c])) for c in comps)
         diff = max(float(_max_abs_diff(prog[c], ref[c])) for c in comps)
         out[f"{prefix}_{fam}"] = diff / scale if scale > 0 else math.inf
     return out
 
 
-def _ref_state(snap):
-    """Reference inputs from a program state dict (the program's public
-    dict form: E/H components, psi_E/psi_H slabs keyed ``<comp>_<axis>``
-    as lo ++ hi planes along that axis, the incident line, t)."""
-    def own(x):     # a copy: the reference consumes (donates) its inputs
-        return jnp.array(x, dtype=jnp.float32, copy=True)
-
-    E = {c: own(snap["E"][c]) for c in E_COMPS}
-    H = {c: own(snap["H"][c]) for c in H_COMPS}
-    psi = {}
-    psi.update(snap.get("psi_E", {}))
-    psi.update(snap.get("psi_H", {}))
-    aux = {"psi": {k: own(v) for k, v in psi.items()},
-           "Einc": own(snap["inc"]["Einc"]),
-           "Hinc": own(snap["inc"]["Hinc"])}
-    return E, H, aux, int(snap["t"])
+def _release(w):
+    """Free the program's device state before the reference runs. The
+    simulation keeps its shapes and shardings, so calibrate.py can
+    reseed it."""
+    w.sim.state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=x.sharding), w.sim.state)
+    gc.collect()
 
 
 def _copy_state(st):
@@ -113,11 +126,13 @@ class Segment(Check):
         self.b = _copy_state(w.sim.state)
 
     def compare(self, w):
-        ref = YeeReference(w.phys)
-        E, H, aux, t0 = _ref_state(self.a)
+        _release(w)
+        ref = self.ref_mod.Reference(w.phys)
+        E, H, aux, t0 = self.from_program(ref, w, self.a)
+        del self.a
         prog = {**self.b["E"], **self.b["H"]}
         E, H, aux, _ = ref.advance(E, H, aux, t0, int(self.b["t"]) - t0)
-        return family_gaps(prog, {**E, **H}, "field_gap")
+        return family_gaps(prog, {**E, **H}, "field_gap", self.ref_mod)
 
 
 def read_dat(path):
@@ -164,18 +179,22 @@ class Dump(Check):
             self.snap = _copy_state(w.sim.state)
 
     def compare(self, w):
+        comps = self.ref_mod.E_COMPS + self.ref_mod.H_COMPS
         dump = {c: read_dat(os.path.join(w.cfg.output.save_dir,
                                          f"{c}_t{self.step:06d}.dat"))
-                for c in E_COMPS + H_COMPS}
+                for c in comps}
         held = {**self.snap["E"], **self.snap["H"]}
         out = {"dump_readback_gap": max(
             float(np.max(np.abs(dump[c] - np.asarray(held[c], np.float32))))
             for c in dump)}
-        ref = YeeReference(w.phys)
-        E, H, aux, t0 = _ref_state(self.pre)
+        del self.snap, held
+        _release(w)
+        ref = self.ref_mod.Reference(w.phys)
+        E, H, aux, t0 = self.from_program(ref, w, self.pre)
+        del self.pre
         E, H, aux, _ = ref.advance(E, H, aux, t0, self.step - t0)
-        out.update(family_gaps({c: jnp.asarray(v) for c, v in dump.items()},
-                               {**E, **H}, "dump_gap"))
+        out.update(family_gaps(w.layout.put(dump), {**E, **H}, "dump_gap",
+                               self.ref_mod))
         return out
 
 
@@ -197,19 +216,15 @@ class Trajectory(Check):
         t_end = int(final["t"])
         acc_prog = dict(w.ntff.acc) if w.ntff is not None else None
         ntff_cfg = self.spec.get("ntff")
-        # the final fields go to the host and the program's device state
-        # is freed before the reference runs; the simulation keeps its
-        # shapes, so calibrate.py can reseed it
+        # the final fields go to the host, the device state is freed
         prog = {c: np.asarray(v) for g in ("E", "H")
                 for c, v in final[g].items()}
-        w.sim.state = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), final)
         del final
-        gc.collect()
-        ref = YeeReference(w.phys)
-        f0 = seeding.initial_fields(w.phys, self.seed)
-        E = {c: f0[c] for c in E_COMPS}
-        H = {c: f0[c] for c in H_COMPS}
+        _release(w)
+        ref = self.ref_mod.Reference(w.phys)
+        f0 = w.layout.make(*seeding.maker(w.phys, self.seed))
+        E = {c: f0[c] for c in self.ref_mod.E_COMPS}
+        H = {c: f0[c] for c in self.ref_mod.H_COMPS}
         del f0
         ntff = None
         if acc_prog is not None:
@@ -218,9 +233,11 @@ class Trajectory(Check):
             every = max(1, round(period_steps / ntff_cfg["samples_per_period"]))
             ntff = {"box": box, "every": every,
                     "start": int(ntff_cfg["start"]), "omega": ref.omega,
-                    "acc": ref.zero_ntff_acc(box)}
-        E, H, aux, acc = ref.advance(E, H, ref.zero_aux(), 0, t_end, ntff)
-        out = family_gaps(prog, {**E, **H}, "field_gap")
+                    "acc": w.layout.make(lambda: ref.zero_ntff_acc(box))}
+        E, H, aux, acc = ref.advance(E, H, w.layout.make(ref.zero_aux), 0,
+                                     t_end, ntff)
+        out = family_gaps(w.layout.put(prog), {**E, **H}, "field_gap",
+                          self.ref_mod)
         if acc_prog is not None:
             for fam in ("E", "H"):
                 keys = [k for k in acc if k[2][0] == fam]

@@ -16,8 +16,9 @@ import re
 
 
 def device(ctx):
-    devs = ctx["trace"].devices()
-    return devs[0] if devs else None
+    """The device whose chunk program has the most device time: with
+    several, the one that sets the pace."""
+    return ctx["trace"].pace_device()
 
 
 def runs(ctx):
@@ -80,11 +81,14 @@ def peak_bytes_per_s(ctx):
 
 
 def roofline(ctx, kind, seconds_per_step):
-    """100 x least time per step (the kind's bytes per step at the
-    chip's published HBM bandwidth) over the measured seconds per step.
-    FDTD is bandwidth-bound: a few flops per byte, far under the v5e's
-    240 flop/byte ridge."""
+    """100 x least time per step over the measured seconds per step.
+    The least time is the kind's bytes per step of the whole grid at
+    the published HBM bandwidth of the chips the program split the grid
+    over (``shards``, from the topology it resolved) together: each
+    moves its part while the device that sets the pace is timed. FDTD is bandwidth-bound: a few flops per byte,
+    far under the v5e's 240 flop/byte ridge."""
     if seconds_per_step is None:
         return None
-    least = kind_module(kind).bytes_per_step(ctx) / peak_bytes_per_s(ctx)
+    least = kind_module(kind).bytes_per_step(ctx) / (
+        peak_bytes_per_s(ctx) * ctx["shards"])
     return 100.0 * least / seconds_per_step

@@ -56,21 +56,25 @@ def classify(entry):
 
 
 def chunk_ops(ctx):
-    """[(start, end, class)] of the chunk module's ops in the window,
-    container ops (a scan's ``while``) left out; None where the program
-    gives no scope map or the trace has no such module."""
+    """[(start, end, class)] of the chunk module's ops in the window on
+    the device where that module ran longest, container ops (a scan's
+    ``while``) left out; None where the program gives no scope map or
+    the trace has no such module."""
     tab = table()
     tr = ctx["trace"]
-    devs = tr.devices()
-    if tab is None or not devs:
+
+    def chunk(name):
+        return name.split("(")[0] == CHUNK
+
+    dev = tr.pace_device(chunk)
+    if tab is None or dev is None:
         return None
-    runs = [m for m in tr.module_runs(devs[0])
-            if m[2].split("(")[0] == CHUNK]
+    runs = [m for m in tr.module_runs(dev) if chunk(m[2])]
     if not runs:
         return None
     lo, hi = tr.win
     return [(max(s, lo), min(e, hi), classify(tab.get(name)))
-            for s, e, name, label, _ in tr.ops_within(devs[0], runs)
+            for s, e, name, label, _ in tr.ops_within(dev, runs)
             if label is not None and e > lo and s < hi]
 
 

@@ -30,6 +30,27 @@ host-side constants (profiles, source samples, phases) are float64 and
 rounded once. Every number it needs comes from the configuration's
 ``physics`` block, so the program's defaults are stated there, not
 looked up.
+
+The contract of a reference module (a configuration names its own in
+its ``reference`` key; benchmark/checks.py uses nothing else of it):
+
+* ``E_COMPS`` and ``H_COMPS``, the component names of the two families
+  the checks compare;
+* ``Reference(phys)``, built from the configuration's ``physics``
+  block, with ``dt`` and ``omega`` (s, rad/s) and
+  ``advance(E, H, aux, t0, n, ntff=None) -> (E, H, aux, acc)``, which
+  consumes its inputs; ``zero_aux()``, the state beside E and H at step
+  0, whose ``psi`` holds ``<comp>_<axis>`` slabs as lo ++ hi planes of
+  the whole grid along <axis>; ``ntff_box(margin)``;
+  ``zero_ntff_acc(box)`` keyed ``(axis, side, comp)`` as the program's
+  NTFF accumulators; and ``from_program(state) -> (E, H, aux, t)``,
+  which takes the program's state dict with psi in that global layout,
+  returns float32 copies of every family it models, and raises on any
+  other family, never dropping one.
+
+Given inputs sharded over a mesh, the arithmetic below stays plain
+``jax.numpy``: the compiler partitions it, and E and H come out of
+``advance`` sharded as they went in.
 """
 
 from __future__ import annotations
@@ -257,6 +278,43 @@ class YeeReference:
                 "Einc": jnp.zeros(self.n_inc, jnp.float32),
                 "Hinc": jnp.zeros(self.n_inc, jnp.float32)}
 
+    FAMILIES = ("E", "H", "psi_E", "psi_H", "inc", "t")
+
+    def from_program(self, state: Dict):
+        """(E, H, aux, t) from the program's state dict (components of
+        E and H, ``psi_E``/``psi_H`` in the global lo ++ hi layout, the
+        incident line, t): float32 copies, since ``advance`` consumes
+        its inputs. A family this reference does not model raises."""
+        extra = sorted(set(state) - set(self.FAMILIES))
+        if extra:
+            raise ValueError(f"the program's state holds {extra}, which "
+                             f"this reference does not model")
+        for fam, comps in (("E", E_COMPS), ("H", H_COMPS)):
+            if set(state[fam]) != set(comps):
+                raise ValueError(f"the program's {fam} holds "
+                                 f"{sorted(state[fam])}, not {comps}")
+        psi = {**state.get("psi_E", {}), **state.get("psi_H", {})}
+        want = jax.eval_shape(self.zero_aux)["psi"]
+        if set(psi) != set(want) or any(
+                psi[k].shape != want[k].shape for k in want):
+            raise ValueError(
+                "the program's psi is not this reference's: "
+                f"{ {k: tuple(v.shape) for k, v in sorted(psi.items())} }")
+
+        line = {k: state["inc"][k] for k in ("Einc", "Hinc")}
+        if any(v.shape != (self.n_inc,) for v in line.values()):
+            raise ValueError("the program's incident line is not "
+                             f"{self.n_inc} samples long")
+
+        def own(x):
+            return jnp.array(x, dtype=jnp.float32, copy=True)
+
+        E = {c: own(state["E"][c]) for c in E_COMPS}
+        H = {c: own(state["H"][c]) for c in H_COMPS}
+        aux = {"psi": {k: own(v) for k, v in psi.items()},
+               **{k: own(v) for k, v in line.items()}}
+        return E, H, aux, int(state["t"])
+
     # -- one step -----------------------------------------------------------
 
     def _eps_mask(self, comp):
@@ -478,3 +536,6 @@ class YeeReference:
             acc[k] = (jnp.zeros(shape, jnp.float32),
                       jnp.zeros(shape, jnp.float32))
         return acc
+
+
+Reference = YeeReference

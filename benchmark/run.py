@@ -10,8 +10,10 @@ in a file of its own under benchmark/), builds the program's
 the window uses, advances the simulation for ``--seconds`` through
 ``Simulation.run`` with an ``on_interval`` that is a copy of the CLI's
 own loop (cadences, gcd chunking, NTFF samples, norms, dumps), and
-then checks what the window produced against the plain reference in
-benchmark/reference/. The last stdout line is the result.
+then checks what the window produced against the plain reference that
+the configuration names (benchmark/reference/). On a cell of several
+chips, everything the harness holds is sharded as the program's state
+is. The last stdout line is the result.
 
 ``--trace 1`` captures a profiler trace of the window and reports the
 cell's per-layer metrics (benchmark/metrics/<name>.py) instead of its
@@ -101,9 +103,13 @@ def load_cell(workload, bench_file=BENCH_FILE):
                                       f"metric {m['name']}")
                for m in per_layer}
     peaks = _load_json(os.path.join(HERE, "peaks.json"), "peak table")
+    if "reference" not in config:
+        raise SpecError(f"config {cell['config']}: no reference key")
+    reference = load_module(os.path.join(HERE, config["reference"]),
+                            f"reference of config {cell['config']}")
     return {"cell": cell, "config": config, "traffic": traffic,
             "limits": limits, "end_to_end": e2e, "per_layer": per_layer,
-            "readers": readers, "peaks": peaks}
+            "readers": readers, "peaks": peaks, "reference": reference}
 
 
 def cell_args(spec, rehearse, dtype=None, save_dir=None):
@@ -193,15 +199,25 @@ class Window:
         """Start over at step 0 from the seeded initial fields: the data
         the comparison sees, made on the device in one jitted call and
         stored in the program's storage type; every other state leaf
-        (CPML psi, the incident line) zero."""
+        (CPML psi, the incident line) zero. On a mesh every leaf is
+        made with the sharding the program gave it, and ``layout``
+        places the harness's own arrays alike."""
         import jax
         import jax.numpy as jnp
+        import layout
         import seeding
         st = self.sim.state
-        fields = seeding.initial_fields(self.phys, seed)
+        sharded = self.sim.mesh is not None
+        self.layout = layout.Layout(next(iter(st["E"].values())).sharding,
+                                    self.cfg.grid_shape)
+        fields = self.layout.make(*seeding.maker(self.phys, seed))
+
+        def zero(x):
+            return jnp.zeros(x.shape, x.dtype,
+                             device=x.sharding if sharded else None)
+
         new = {k: ({c: fields[c].astype(v[c].dtype) for c in v}
-                   if k in ("E", "H") else jax.tree.map(
-                       lambda x: jnp.zeros(x.shape, x.dtype), v))
+                   if k in ("E", "H") else jax.tree.map(zero, v))
                for k, v in st.items()}
         del st, fields
         self.sim.state = new
@@ -326,7 +342,7 @@ def measure(w, spec, seed, seconds, tracer=None):
     """One seed on a built Window: warm every program the window runs,
     then run the window. Shared by run() and calibrate.py."""
     import checks
-    check = checks.make(spec["traffic"]["check"], seed)
+    check = checks.make(spec["traffic"]["check"], seed, spec["reference"])
     t0 = time.perf_counter()
     check.warm_up(w)
     w.sim.block_until_ready()
@@ -423,7 +439,9 @@ def run(opts):
                "build_s": w.build_s, "warmup_s": r["warmup_s"],
                "grid_shape": tuple(w.cfg.grid_shape),
                "dtype": w.cfg.dtype, "physics": w.phys,
-               "device_kind": device["kind"], "peaks": spec["peaks"],
+               "device_kind": device["kind"],
+               "shards": math.prod(w.sim.topology),
+               "peaks": spec["peaks"],
                "rehearse": opts.rehearse, **info}
         for m in spec["per_layer"]:
             v = spec["readers"][m["name"]].read(ctx)

@@ -57,13 +57,23 @@ def _make(size):
 _GEN = {}
 
 
-def initial_fields(phys, seed):
+def maker(phys, seed):
+    """(gen, params): ``gen(params)`` makes the seed's fields. ``gen``
+    is one jitted program per grid size, the same for every seed, so a
+    caller that places its outputs (``layout.Layout.make``) compiles it
+    once."""
     size = tuple(int(v) for v in phys["size"])
     if size not in _GEN:
         _GEN[size] = _make(size)
     params = {c: tuple(jnp.asarray(x) for x in p)
               for c, p in blob_params(size, seed).items()}
-    return _GEN[size](params)
+    return _GEN[size], params
+
+
+def initial_fields(phys, seed):
+    """{comp: float32 field} of the seed, on the default device."""
+    gen, params = maker(phys, seed)
+    return gen(params)
 
 
 def draw(seed, lo, hi, salt):

@@ -2,11 +2,14 @@
 underneath (CPU rehearsal sizes): the harness's chip check is skipped
 by --rehearse, everything else runs as in a real run.
 
-    python3 benchmark/tests/fault_driver.py FAULT -- <run.py args>
+    python3 benchmark/tests/fault_driver.py FAULT [--bench PATH] \
+        -- <run.py args>
 
 FAULT is ``unchanged`` (every chunk returns its state as it got it,
-the step counter still advancing) or ``altered`` (one field value of
-every chunk's result is changed where the chunk produces it)."""
+the step counter still advancing), ``altered`` (one field value of
+every chunk's result is changed where the chunk produces it) or
+``none``. ``--bench`` reads the cells from another BENCHMARK.json (a
+test's scratch cells)."""
 
 import os
 import sys
@@ -45,9 +48,15 @@ def patch(fault):
 
 if __name__ == "__main__":
     fault = sys.argv[1]
-    assert sys.argv[2] == "--"
-    opts = run.parse(sys.argv[3:])
+    sep = sys.argv.index("--")
+    if sys.argv[2:sep]:
+        assert sys.argv[2] == "--bench" and sep == 4
+        bench_file = sys.argv[3]
+        load_cell = run.load_cell
+        run.load_cell = lambda workload: load_cell(workload, bench_file)
+    opts = run.parse(sys.argv[sep + 1:])
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax  # noqa: F401
-    patch(fault)
+    if fault != "none":
+        patch(fault)
     sys.exit(run.run(opts))

@@ -1,6 +1,8 @@
 """Self-checks of the benchmark (run them with
 ``JAX_PLATFORMS=cpu python3 -m pytest benchmark/tests -q``; the repo's
-tier-1 suite does not collect this directory)."""
+tier-1 suite does not collect this directory). This process has four
+CPU devices (conftest.py); each rehearsal runs in a child process with
+one, or with four where its cell asks for four chips."""
 
 import copy
 import json
@@ -17,18 +19,20 @@ sys.path.insert(0, BENCH)
 
 import run  # noqa: E402
 import tracing  # noqa: E402
+from conftest import cpu_env  # noqa: E402
 
 CELLS = ("vacuum256.long", "mie512.ntff", "vacuum256.snap")
-ENV = dict(os.environ, JAX_PLATFORMS="cpu")
+ENV = cpu_env(1)
+FAULT_DRIVER = os.path.join(HERE, "fault_driver.py")
 
 
 def _last_json(out):
     return json.loads(out.strip().splitlines()[-1])
 
 
-def _run(args, timeout=900, driver=None):
+def _run(args, timeout=900, driver=None, env=ENV):
     cmd = [sys.executable] + ([driver] if driver else []) + args
-    p = subprocess.run(cmd, cwd=ROOT, env=ENV, capture_output=True,
+    p = subprocess.run(cmd, cwd=ROOT, env=env, capture_output=True,
                        text=True, timeout=timeout)
     return p
 
@@ -98,7 +102,7 @@ def _ctx(cfg_name, kind, k=None, rehearse=False):
         BENCH, "configs", cfg_name + ".json")))}
     phys = run.cell_physics(spec, rehearse)
     return {"grid_shape": tuple(phys["size"]), "physics": phys,
-            "dtype": "float32", "step_kind": kind,
+            "dtype": "float32", "step_kind": kind, "shards": 1,
             "step_diag": {"temporal_block": k} if k else {}}
 
 
@@ -162,7 +166,7 @@ def test_bfloat16_control_is_not_correct(cell):
 def test_broken_timed_path_is_not_correct(cell, fault):
     p = _run([fault, "--", "--workload", cell, "--seed",
               "12", "--seconds", "2", "--trace", "0", "--rehearse"],
-             driver=os.path.join(HERE, "fault_driver.py"))
+             driver=FAULT_DRIVER)
     res = _last_json(p.stdout)
     assert res["correct"] is False, (res["checks"], p.stderr[-2000:])
 
@@ -172,10 +176,60 @@ def test_traced_rehearsal_reports_per_layer_metrics_it_can_read():
               "5", "--seconds", "2", "--trace", "1", "--rehearse"])
     assert p.returncode == 0, p.stderr[-3000:]
     res = _last_json(p.stdout)
-    # the CPU has no device line: only the host-clock metrics are read
-    assert set(res["metrics"]) == {"build_s", "warmup_s"}
+    # the CPU has no device line: every metric the cell lists that the
+    # host clock, the program's spans or its counters give is read,
+    # and none that the device trace gives
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    want = {m["name"] for m in bench["per_layer"]
+            if "vacuum256.long" in m.get("workloads", ["vacuum256.long"])
+            and m["source"] in ("host_clock", "program_span",
+                                "program_counter")}
+    assert set(res["metrics"]) == want
     assert {"busy_s", "window_s"} <= set(res["device"])
     assert "breakdown" in res
+
+
+# -- four-chip cells, rehearsed on four CPU devices --------------------------
+
+def _four_chip_bench(tmp_path):
+    """Scratch four-chip cells: ``vacuum256`` plus ``--topology auto``
+    under ``long`` (segment check) and ``snap`` (dump check), and
+    ``mie512`` (already ``--topology auto``) under ``ntff`` (trajectory
+    check). At the rehearsal sizes the program resolves (1,2,2)."""
+    cfg = json.load(open(os.path.join(BENCH, "configs", "vacuum256.json")))
+    cfg["args"] += ["--topology", "auto"]
+    cfg_path = tmp_path / "vacuum256x4.json"
+    cfg_path.write_text(json.dumps(cfg))
+
+    def edit(b):
+        b["configs"].append(dict(b["configs"][0], name="vacuum256x4",
+                                 file=str(cfg_path)))
+        for w in b["workloads"]:
+            w["chips"] = 4
+            if w["config"] == "vacuum256":
+                w["config"] = "vacuum256x4"
+    return _bench_copy(tmp_path, edit)
+
+
+@pytest.mark.parametrize("mode", ("sound", "bfloat16", "unchanged",
+                                  "altered"))
+@pytest.mark.parametrize("cell", CELLS)
+def test_four_chip_rehearsal(tmp_path, cell, mode):
+    """The checks of a sharded cell: a sound run is correct, the
+    bfloat16 control and each planted fault are not."""
+    fault = mode if mode in ("unchanged", "altered") else "none"
+    args = [fault, "--bench", _four_chip_bench(tmp_path), "--",
+            "--workload", cell, "--seed", "3000000041", "--seconds", "2",
+            "--trace", "0", "--rehearse"]
+    if mode == "bfloat16":
+        args += ["--dtype", "bfloat16"]
+    p = _run(args, driver=FAULT_DRIVER, env=cpu_env(4))
+    res = _last_json(p.stdout)
+    assert res["device"]["count"] == 4
+    assert res["correct"] is (mode == "sound"), (res["checks"],
+                                                 p.stderr[-3000:])
+    if mode == "sound":
+        assert res["checks"], p.stderr[-3000:]
 
 
 def test_no_accelerator_no_result():
@@ -226,6 +280,30 @@ def test_missing_metric_reader_fails(tmp_path):
         run.load_cell("vacuum256.long", _bench_copy(tmp_path, edit))
 
 
+def test_missing_reference_fails(tmp_path):
+    cfg = json.load(open(os.path.join(BENCH, "configs", "vacuum256.json")))
+    cfg["reference"] = "reference/no_such_reference.py"
+    path = tmp_path / "vacuum256.json"
+    path.write_text(json.dumps(cfg))
+
+    def edit(b):
+        b["configs"][0]["file"] = str(path)
+    with pytest.raises(run.SpecError, match="no_such_reference"):
+        run.load_cell("vacuum256.long", _bench_copy(tmp_path, edit))
+
+
+def test_the_reference_is_the_one_the_config_names():
+    spec = run.load_cell("vacuum256.long")
+    assert spec["reference"].__file__ == os.path.join(
+        BENCH, "reference", "yee.py")
+    import re
+
+    import checks
+    with open(checks.__file__) as f:
+        assert not re.search(r"^\s*(from|import)\s+reference", f.read(),
+                             re.M)
+
+
 def test_missing_config_fails(tmp_path):
     def edit(b):
         b["configs"][0]["file"] = "benchmark/configs/nothing.json"
@@ -238,6 +316,9 @@ class _OneOpTrace:
 
     def devices(self):
         return ["/device:TPU:0"]
+
+    def pace_device(self, keep=None):
+        return "/device:TPU:0"
 
     def busiest_module(self, dev):
         return "m"
@@ -278,6 +359,19 @@ def test_unknown_step_kind_is_an_error(metric):
         mod.read(_roofline_ctx("TPU v5 lite", "no_such_kind"))
 
 
+@pytest.mark.parametrize("metric", ("step_roofline",
+                                    "pallas_packed_tb_roofline"))
+def test_chips_share_the_grids_bytes(metric):
+    """Split over four shards, each chip moves a quarter of the grid's
+    bytes in the time the trace gives: the same trace reads a quarter
+    of the share."""
+    mod = run.load_module(run.reader_path(metric), metric)
+    one = mod.read(_roofline_ctx("TPU v5 lite", "pallas_packed_tb"))
+    four = mod.read(dict(_roofline_ctx("TPU v5 lite", "pallas_packed_tb"),
+                         shards=4))
+    assert four == pytest.approx(one / 4, rel=1e-12)
+
+
 def test_a_kernel_roofline_is_silent_without_its_kernel():
     mod = run.load_module(run.reader_path("pallas_packed_roofline"), "r")
     assert mod.read(_roofline_ctx("TPU v5 lite", "pallas_packed_tb")) is None
@@ -287,6 +381,66 @@ def test_a_split_metric_uses_its_base_reader():
     assert run.reader_path("device_idle_share.io") == os.path.join(
         BENCH, "metrics", "device_idle_share.py")
     assert run.reader_path("no_such.metric").endswith("no_such.py")
+
+
+def _two_device_events():
+    """A window from 0 to 10,000 us on two devices. Each runs the chunk
+    module ``jit_fdtd3d_chunk(7)`` once, device 0 for 1,000 us and
+    device 1 for 3,000 us, and a small executable (400 us on device 0,
+    more than device 1 spends in it). An NTFF sample span covers the
+    start of the chunk on both."""
+    ev = [{"ph": "M", "name": "process_name", "pid": 9,
+           "args": {"name": "/host:CPU"}},
+          {"ph": "X", "pid": 9, "tid": 1, "ts": 0.0, "dur": 10000.0,
+           "name": tracing.WINDOW},
+          {"ph": "X", "pid": 9, "tid": 1, "ts": 1000.0, "dur": 100.0,
+           "name": "fdtd3d/ntff-sample"}]
+    for pid, chunk_us, small_us in ((1, 1000.0, 400.0),
+                                    (2, 3000.0, 200.0)):
+        ev += [{"ph": "M", "name": "process_name", "pid": pid,
+                "args": {"name": f"/device:TPU:{pid - 1}"}},
+               {"ph": "M", "name": "thread_name", "pid": pid, "tid": 1,
+                "args": {"name": "XLA Modules"}},
+               {"ph": "M", "name": "thread_name", "pid": pid, "tid": 2,
+                "args": {"name": "XLA Ops"}},
+               {"ph": "X", "pid": pid, "tid": 1, "ts": 1000.0,
+                "dur": chunk_us, "name": "jit_fdtd3d_chunk(7)"},
+               {"ph": "X", "pid": pid, "tid": 2, "ts": 1000.0,
+                "dur": chunk_us, "name": "fusion.1",
+                "args": {"hlo_category": "loop fusion"}},
+               {"ph": "X", "pid": pid, "tid": 1, "ts": 6000.0,
+                "dur": small_us, "name": "jit_norms(3)"},
+               {"ph": "X", "pid": pid, "tid": 2, "ts": 6000.0,
+                "dur": small_us, "name": "reduce.2",
+                "args": {"hlo_category": "reduce"}}]
+    return ev
+
+
+def test_readers_on_a_two_device_trace(monkeypatch):
+    """With several devices the readers read the one whose chunk
+    program ran longest (device 1 here), not the first."""
+    from metrics import _chunks, _scopes
+    tr = tracing.Trace(_two_device_events())
+    assert tr.devices() == ["/device:TPU:0", "/device:TPU:1"]
+    assert tr.pace_device() == "/device:TPU:1"
+    assert tr.pace_device(lambda n: n.startswith("jit_norms")) == \
+        "/device:TPU:0"
+    ctx = {"trace": tr, "steps": 2, "chunks": 1}
+    assert _chunks.device(ctx) == "/device:TPU:1"
+    step_ms = run.load_module(run.reader_path("step_ms"), "step_ms")
+    assert step_ms.read(ctx) == pytest.approx(3000e-3 / 2)
+    ntff = run.load_module(run.reader_path("ntff_ms_per_sample"), "ntff")
+    assert ntff.read(ctx) == pytest.approx(3.0)
+    monkeypatch.setattr(_scopes, "table",
+                        lambda: {"fusion.1": ("tfsf", frozenset())})
+    assert _scopes.class_s(ctx, ("tfsf",)) == pytest.approx(3000e-6)
+    ops = dict(tr.breakdown()["device_ops"])
+    assert ops["fusion.1"] == pytest.approx(3000e-6)
+    # one device: the readers read it, as before
+    one = tracing.Trace([e for e in _two_device_events()
+                         if e.get("pid") != 2])
+    assert one.pace_device() == "/device:TPU:0"
+    assert step_ms.read(dict(ctx, trace=one)) == pytest.approx(0.5)
 
 
 def test_missing_limit_file_fails(tmp_path):

@@ -195,6 +195,24 @@ class Trace:
             tot[n] = tot.get(n, 0.0) + e - s
         return max(tot, key=tot.get) if tot else None
 
+    def pace_device(self, keep=None):
+        """The device whose chunk program has the most device time in
+        the window: over several devices it sets the pace. The chunk
+        program is each device's busiest executable, or the executables
+        whose name ``keep(name)`` accepts. With one device, that
+        device; the first on a tie; None with none."""
+        best, most = None, -1.0
+        for d in self.devices():
+            if keep is None:
+                busiest = self.busiest_module(d)
+                runs = self.module_runs(d, busiest) if busiest else []
+            else:
+                runs = [r for r in self.module_runs(d) if keep(r[2])]
+            s = sum(e - b for b, e, _ in runs)
+            if s > most:
+                best, most = d, s
+        return best
+
     def ops_where(self, dev, keep):
         """(start, end) of the window's device ops for which
         ``keep(name, source)`` holds, clipped to the window."""
@@ -246,12 +264,12 @@ class Trace:
         return best[2] if best else "host: outside the program's spans"
 
     def breakdown(self):
-        """Top device ops by time and the longest idle gaps, each with
-        at most 10 entries, seconds unrounded."""
-        devs = self.devices()
-        if not devs:
+        """Top device ops by time and the longest idle gaps of the
+        device that sets the pace, each with at most 10 entries,
+        seconds unrounded."""
+        dev = self.pace_device()
+        if dev is None:
             return {"device_ops": [], "idle_gaps": []}
-        dev = devs[0]
         tot = {}
         for s, e, _, label, _ in self.device_ops(dev):
             if label is not None:
